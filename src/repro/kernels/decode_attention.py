@@ -17,14 +17,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["decode_attention_fwd", "decode_attention_paged_fwd"]
 
 _NEG_INF = -1e30
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
 
 
 def _kernel(pos_ref, q_ref, k_ref, v_ref, sp_ref, o_ref, acc_ref, m_ref, l_ref,
             *, scale: float, window: int, n_k_blocks: int):
+    b = pl.program_id(0)
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -36,8 +41,8 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, sp_ref, o_ref, acc_ref, m_ref, l_ref,
     q = q_ref[0, 0].astype(jnp.float32)  # (G, D)
     k = k_ref[0, 0].astype(jnp.float32)  # (block_k, D)
     v = v_ref[0, 0].astype(jnp.float32)
-    sp = sp_ref[0]  # (block_k,) absolute positions (-1 = empty)
-    pos = pos_ref[0]  # scalar query position
+    sp = sp_ref[0, 0]  # (block_k,) absolute positions (-1 = empty)
+    pos = pos_ref[b]  # scalar query position (SMEM)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -85,27 +90,32 @@ def decode_attention_fwd(
     kernel = functools.partial(
         _kernel, scale=scale, window=window, n_k_blocks=n_k
     )
-    out = pl.pallas_call(
-        kernel,
+    # ``pos`` is a scalar-prefetch operand (one SMEM scalar per row) and
+    # ``slot_pos`` rides as (B, 1, S): Mosaic refuses a rank-1 (1,) block
+    # and a (1, block_k) tile of a (B, S) array.
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, NKV, n_k),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ki: (b,)),
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki: (b, h, ki, 0)),
-            pl.BlockSpec((1, block_k), lambda b, h, ki: (b, ki)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, ki, pos: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki, pos: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki, pos: (b, h, ki, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b, h, ki, pos: (b, 0, ki)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ki: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, NKV, G, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ki, pos: (b, h, 0, 0)),
         scratch_shapes=[
-            _vmem((G, D), jnp.float32),
-            _vmem((G,), jnp.float32),
-            _vmem((G,), jnp.float32),
+            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G,), jnp.float32),
         ],
-        compiler_params=_mosaic_params(("parallel", "parallel", "arbitrary")),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, NKV, G, D), q.dtype),
+        compiler_params=_PARAMS,
         interpret=interpret,
-    )(pos, q, k_cache, v_cache, slot_pos)
-    return out
+    )(pos.astype(jnp.int32), q, k_cache, v_cache, slot_pos.reshape(B, 1, S))
 
 
 def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
@@ -178,8 +188,6 @@ def decode_attention_paged_fwd(
     if scale is None:
         scale = D**-0.5
 
-    from jax.experimental.pallas import tpu as pltpu
-
     kernel = functools.partial(
         _paged_kernel, scale=scale, window=window, page=page, n_blocks=NB
     )
@@ -199,31 +207,16 @@ def decode_attention_paged_fwd(
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ki, tbl, pos: (b, h, 0, 0)),
         scratch_shapes=[
-            _vmem((G, D), jnp.float32),
-            _vmem((G,), jnp.float32),
-            _vmem((G,), jnp.float32),
+            pltpu.VMEM((G, D), jnp.float32),
+            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G,), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, NKV, G, D), q.dtype),
-        compiler_params=_mosaic_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(page_tables.astype(jnp.int32), pos.astype(jnp.int32), q, k_pool, v_pool)
     return out
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
-
-
-def _mosaic_params(semantics):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.CompilerParams(dimension_semantics=semantics)
-    except Exception:  # pragma: no cover
-        return None

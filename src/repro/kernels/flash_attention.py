@@ -21,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_fwd"]
 
@@ -78,7 +79,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref, *,
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log(l)
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 def flash_attention_fwd(
@@ -132,40 +133,26 @@ def flash_attention_fwd(
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda b, qi, ki: (b, qi)),
+            # LSE rides as (bh, 1, S): a (1, block_q) tile of a (bh, S)
+            # array is neither (8, 128)-aligned nor full, which Mosaic
+            # refuses; a unit middle dim equals its full extent.
+            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, S, D), q.dtype),
-            jax.ShapeDtypeStruct((bh, S), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, S), jnp.float32),
         ],
         scratch_shapes=[
-            _vmem((block_q, D), jnp.float32),
-            _vmem((block_q,), jnp.float32),
-            _vmem((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
         ],
-        compiler_params=_mosaic_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(qr, _strip_block(k), _strip_block(v))
+    )(qr, k, v)
     out = out.reshape(B, NQ, S, D)
     if return_lse:
         return out, lse.reshape(B, NQ, S)
     return out
-
-
-def _strip_block(x):
-    return x  # (B, NKV, S, D) is already the kernel layout
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
-
-
-def _mosaic_params(semantics):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.CompilerParams(dimension_semantics=semantics)
-    except Exception:  # pragma: no cover - older API fallback
-        return None

@@ -22,10 +22,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["flash_attention_bwd"]
 
 _NEG_INF = -1e30
+_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
 
 
 def _masked_scores(q, k, q_lo, k_lo, scale, causal, window):
@@ -64,8 +68,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]  # (block_q,)
-        delta = delta_ref[0]
+        lse = lse_ref[0, 0]  # (block_q,)
+        delta = delta_ref[0, 0]
         s = _masked_scores(q, k, q_lo, k_lo, scale, causal, window)
         p = jnp.exp(s - lse[:, None])
         dp = jax.lax.dot_general(
@@ -105,8 +109,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        delta = delta_ref[0]
+        lse = lse_ref[0, 0]
+        delta = delta_ref[0, 0]
         s = _masked_scores(q, k, q_lo, k_lo, scale, causal, window)
         p = jnp.exp(s - lse[:, None])  # (block_q, block_k)
         dv_acc[...] += jax.lax.dot_general(
@@ -150,10 +154,12 @@ def flash_attention_bwd(
 
     delta = jnp.einsum(
         "bhsd,bhsd->bhs", dout.astype(jnp.float32), out.astype(jnp.float32)
-    ).reshape(bh, S)
+    ).reshape(bh, 1, S)
     qr = q.reshape(bh, S, D)
     dor = dout.reshape(bh, S, D)
-    lser = lse.reshape(bh, S)
+    # Per-row statistics ride as (bh, 1, S) so their (1, 1, block_q) tiles
+    # end in a full unit dim and a lane-aligned block, as Mosaic requires.
+    lser = lse.reshape(bh, 1, S)
 
     common = dict(scale=scale, causal=causal, window=window,
                   block_q=block_q, block_k=block_k)
@@ -168,13 +174,13 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, qi, ki, NQ=NQ, G=G: (b // NQ, (b % NQ) // G, ki, 0)),
             pl.BlockSpec((1, block_q, D), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda b, qi, ki: (b, qi)),
-            pl.BlockSpec((1, block_q), lambda b, qi, ki: (b, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
         ],
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, qi, ki: (b, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, S, D), q.dtype),
-        scratch_shapes=[_vmem((block_q, D), jnp.float32)],
-        compiler_params=_mosaic_params(("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(qr, k, v, dor, lser, delta)
 
@@ -188,8 +194,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, ki, qi, NQ=NQ, G=G: (b // NQ, (b % NQ) // G, ki, 0)),
             pl.BlockSpec((1, block_q, D), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((1, block_q), lambda b, ki, qi: (b, qi)),
-            pl.BlockSpec((1, block_q), lambda b, ki, qi: (b, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
+            pl.BlockSpec((1, 1, block_q), lambda b, ki, qi: (b, 0, qi)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, ki, qi: (b, ki, 0)),
@@ -200,10 +206,10 @@ def flash_attention_bwd(
             jax.ShapeDtypeStruct((bh, S, D), v.dtype),
         ],
         scratch_shapes=[
-            _vmem((block_k, D), jnp.float32),
-            _vmem((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, D), jnp.float32),
+            pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_mosaic_params(("parallel", "parallel", "arbitrary")),
+        compiler_params=_PARAMS,
         interpret=interpret,
     )(qr, k, v, dor, lser, delta)
 
@@ -212,18 +218,3 @@ def flash_attention_bwd(
     dk = dk_h.reshape(B, NKV, G, S, D).sum(axis=2).astype(k.dtype)
     dv = dv_h.reshape(B, NKV, G, S, D).sum(axis=2).astype(v.dtype)
     return dq, dk, dv
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
-
-
-def _mosaic_params(semantics):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.CompilerParams(dimension_semantics=semantics)
-    except Exception:  # pragma: no cover
-        return None

@@ -1,8 +1,9 @@
 """Jit'd dispatch wrappers: Pallas kernels on TPU, pure-JAX refs elsewhere.
 
-The model code calls these; on the CPU-host dry-run Mosaic cannot lower, so
-dispatch falls back to the references (identical math — the kernels are
-validated against them in interpret mode by tests/test_kernels_*.py).
+The platform picks the path: where JAX runs on a TPU the Mosaic kernels
+run, on any other backend the references do (identical math — the kernels
+are checked against them in interpret mode by tests/test_kernels.py, and
+compiled for v5e at real widths by tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 

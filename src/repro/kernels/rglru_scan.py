@@ -7,40 +7,50 @@ and walk the sequence dimension sequentially — each (a, bx) element is read
 from HBM exactly once and h is written once, i.e. the kernel runs at HBM
 bandwidth.  We adopt exactly that structure: grid = (B, n_width_blocks,
 n_seq_blocks) with the sequence dimension "arbitrary" (sequential), and an
-in-kernel ``fori_loop`` over the rows of the current block while the carry
-lives in VMEM scratch.
+in-kernel ``fori_loop`` over 8-row tiles of the current block while the
+carry lives in VMEM scratch.
 
 (The pure-JAX path uses ``associative_scan`` — O(log S) depth but ~2x the
-HBM traffic; the trade is recorded in DESIGN.md and EXPERIMENTS.md §Perf.)
+HBM traffic.)
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["rglru_scan_fwd"]
 
 
-def _kernel(a_ref, b_ref, h0_ref, o_ref, carry_ref, *, block_s: int, n_s: int):
+def _kernel(a_ref, b_ref, h0_ref, o_ref, carry_ref, *, block_s: int, rows: int):
     si = pl.program_id(2)
 
     @pl.when(si == 0)
     def _init():
-        carry_ref[...] = h0_ref[0].astype(jnp.float32)
+        carry_ref[...] = h0_ref[0].astype(jnp.float32)  # (1, block_w)
 
-    a = a_ref[0].astype(jnp.float32)  # (block_s, block_w)
-    b = b_ref[0].astype(jnp.float32)
-
-    def step(t, h):
-        h = a[t] * h + b[t]
-        o_ref[0, t, :] = h.astype(o_ref.dtype)
+    # The sequence is walked ``rows`` at a time: each tile is loaded and
+    # stored at a sublane-aligned offset (Mosaic cannot prove a single-row
+    # offset aligned for packed dtypes), and its rows are stepped in a
+    # static unroll.
+    def tile(i, h):
+        rs = pl.ds(pl.multiple_of(i * rows, rows), rows)
+        a = a_ref[0, rs, :].astype(jnp.float32)  # (rows, block_w)
+        b = b_ref[0, rs, :].astype(jnp.float32)
+        hs = []
+        for r in range(rows):
+            h = a[r : r + 1] * h + b[r : r + 1]
+            hs.append(h)
+        o_ref[0, rs, :] = jnp.concatenate(hs, axis=0).astype(o_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, block_s, step, carry_ref[...])
-    carry_ref[...] = h
+    carry_ref[...] = jax.lax.fori_loop(
+        0, block_s // rows, tile, carry_ref[...]
+    )
 
 
 def rglru_scan_fwd(a, b, h0, *, block_s: int = 128, block_w: int = 512,
@@ -55,34 +65,25 @@ def rglru_scan_fwd(a, b, h0, *, block_s: int = 128, block_w: int = 512,
     assert S % block_s == 0 and W % block_w == 0
     n_s, n_w = S // block_s, W // block_w
 
-    kernel = functools.partial(_kernel, block_s=block_s, n_s=n_s)
+    kernel = functools.partial(
+        _kernel, block_s=block_s, rows=math.gcd(block_s, 8)
+    )
     out = pl.pallas_call(
         kernel,
         grid=(B, n_w, n_s),
         in_specs=[
             pl.BlockSpec((1, block_s, block_w), lambda b_, wi, si: (b_, si, wi)),
             pl.BlockSpec((1, block_s, block_w), lambda b_, wi, si: (b_, si, wi)),
-            pl.BlockSpec((1, block_w), lambda b_, wi, si: (b_, wi)),
+            # h0 rides as (B, 1, W): a (1, block_w) tile of a (B, W) array
+            # is neither (8, 128)-aligned nor full, which Mosaic refuses.
+            pl.BlockSpec((1, 1, block_w), lambda b_, wi, si: (b_, 0, wi)),
         ],
         out_specs=pl.BlockSpec((1, block_s, block_w), lambda b_, wi, si: (b_, si, wi)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), a.dtype),
-        scratch_shapes=[_vmem((block_w,), jnp.float32)],
-        compiler_params=_mosaic_params(("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(a, b, h0)
+    )(a, b, h0.reshape(B, 1, W))
     return out
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
-
-
-def _mosaic_params(semantics):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.CompilerParams(dimension_semantics=semantics)
-    except Exception:  # pragma: no cover
-        return None

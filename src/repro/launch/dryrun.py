@@ -363,7 +363,7 @@ def run_cell(arch, shape, mesh_kind, *, with_components=True, verbose=True,
             )
         )
     )
-    full_ca = rf.cost_analysis_dict(compiled)
+    full_ca = compiled.cost_analysis()
 
     row = {
         "arch": arch,

@@ -35,7 +35,6 @@ import numpy as np
 __all__ = [
     "HW",
     "collective_bytes",
-    "cost_analysis_dict",
     "cost_terms",
     "CellReport",
     "combine_components",
@@ -96,20 +95,8 @@ class Component:
     multiplier: float = 1.0
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns a one-element list of per-module dicts; newer jax
-    returns the dict directly.  Either way: a (possibly empty) dict.
-    """
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def component_from_compiled(name: str, compiled, multiplier: float = 1.0) -> Component:
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     return Component(
         name=name,
         flops=float(ca.get("flops", 0.0)),

@@ -1,13 +1,14 @@
 """End-to-end serving driver: MDInference over real model variants.
 
-Builds N functionally-equivalent LM tiers (tiny reduced configs at
-different widths/depths on CPU), measures their real latency profiles
-(Table III methodology), then serves an open-loop request stream with
-continuous batching: arrivals come from a Poisson (or bursty) load
-generator over a network model, each scheduling window is decided in one
-batched scheduler call, requests that picked the same tier execute as one
-real ``generate`` batch, and the hedge tier bounds every response at the
-SLA.
+Builds N functionally-equivalent LM tiers on whatever device JAX runs on,
+CPU or TPU (by default the reduced same-family configs of :data:`TIERS`;
+``build_engine`` takes any tier list, published widths included), measures
+their real latency profiles (Table III methodology), then serves an
+open-loop request stream with continuous batching: arrivals come from a
+Poisson (or bursty) load generator over a network model, each scheduling
+window is decided in one batched scheduler call, requests that picked the
+same tier execute as one real ``generate`` batch, and the hedge tier bounds
+every response at the SLA.
 
 Two-tier execution: the remote tiers run on a ``JitBackend``; the hedge
 duplicate runs *for real* on an ``OnDeviceBackend`` (the zoo's tiny
@@ -43,6 +44,11 @@ AIMD with hysteresis); ``--replica-spec '2:8:0.5,1'`` declares a
 heterogeneous pool (per-replica weight / soft concurrency cap / service
 scale) that the load-aware routers account for.
 
+Replicas stay in this process: ``--replicas N`` places replica *i* on
+``jax.devices()[i % n]``.  ``--transport process`` spawns a worker per
+replica and therefore runs on the CPU only — a TPU chip belongs to the one
+process that opened it.
+
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --requests 50 --sla 2000
 """
@@ -50,6 +56,8 @@ from __future__ import annotations
 
 import argparse
 import functools
+import os
+import pathlib
 import time
 
 import jax
@@ -68,7 +76,7 @@ from repro.serving.cluster import (
     shard_slices,
 )
 from repro.serving.controller import AdmissionController, ControllerConfig
-from repro.serving.transport import ProcessTransportBackend
+from repro.serving.transport import PROCESS_ON_TPU, ProcessTransportBackend
 from repro.serving.engine import ServingEngine, Variant
 from repro.serving.loadgen import (
     BurstyArrivals,
@@ -80,17 +88,63 @@ from repro.serving.loadgen import (
 from repro.serving.scheduler import MDInferenceScheduler, SchedulerConfig
 from repro.serving.tenancy import parse_tenant_spec
 
+
+def _reduced_tier(name, arch, width, layers, quality):
+    cfg = reduced(
+        arch, d_model=width, n_layers=layers,
+        n_heads=4, n_kv_heads=2, head_dim=width // 4,
+    )
+    return (name, cfg, quality)
+
+
+# The default zoo: (name, ModelConfig, quality-proxy) — reduced configs of
+# three families at widths 64/128/256.
 TIERS = (
-    # (name, arch family, width, layers, quality-proxy)
-    ("tier-s", "gemma-2b", 64, 2, 42.0),
-    ("tier-m", "llama3-8b", 128, 4, 68.0),
-    ("tier-l", "qwen3-14b", 256, 6, 77.0),
+    _reduced_tier("tier-s", "gemma-2b", 64, 2, 42.0),
+    _reduced_tier("tier-m", "llama3-8b", 128, 4, 68.0),
+    _reduced_tier("tier-l", "qwen3-14b", 256, 6, 77.0),
 )
 
+# The checkout root: src/repro/launch/serve.py -> three levels up.
+_CHECKOUT_ROOT = pathlib.Path(__file__).resolve().parents[3]
 
-def _jit_backend_factory(max_len: int) -> JitBackend:
-    """Top-level (picklable) backend factory for the process transport."""
-    return JitBackend(max_len)
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the deployment's choice:
+    JAX already reads it, and nothing else is set.  Otherwise the cache is
+    the fixed ``.jax_cache/`` at the checkout root — a fixed path, because
+    the path is part of what a later run must find again.  Call it before
+    the first compile, from an entry point (never at import).
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(_CHECKOUT_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def _jit_backend_factory(max_len: int, replica: int) -> JitBackend:
+    """Top-level (picklable) backend factory: replica *i* computes on
+    ``jax.devices()[i % n]`` of the process that builds it."""
+    devices = jax.devices()
+    return JitBackend(max_len, device=devices[replica % len(devices)])
+
+
+def prewarm_hedge(engine: ServingEngine, n_slots: int, prompt_len: int,
+                  gen: int) -> None:
+    """Compile the hedge tier at every power-of-two tick shape up to
+    ``n_slots`` rows: its first inline compile otherwise burns real
+    wall-clock SLA budget mid-race and spuriously releases hedged slots."""
+    hedge = engine.hedge_backend
+    N = 1
+    while N <= n_slots:
+        hedge.run_batch(
+            hedge.hedge_name, np.zeros((N, prompt_len), np.int32), gen
+        )
+        N *= 2
 
 
 def _export_observability(obs, trace_out, metrics_out) -> None:
@@ -127,13 +181,16 @@ def build_engine(
     max_len: int, seed: int = 0, measured_hedge: bool = True,
     dispatch: str = "async", replicas: int = 1, router: str = "round_robin",
     shard_zoo: bool = False, transport: str = "none",
-    geometry=None, specs=None,
+    geometry=None, specs=None, tiers=TIERS,
 ) -> ServingEngine:
+    """Build the serving engine over ``tiers`` — ``(name, ModelConfig,
+    quality)`` triples, each registered with seeded random weights."""
     hedge = (
         OnDeviceBackend.from_zoo(max_len=max_len, seed=seed)
         if measured_hedge
         else None
     )
+    backend = None
     if geometry is not None:
         # Continuous-batching remote tier: fixed-shape compiled
         # prefill/decode entries over a block-paged slot cache; requests
@@ -142,49 +199,43 @@ def build_engine(
             max_len=max_len, hedge_backend=hedge, dispatch=dispatch,
             continuous=True, geometry=geometry,
         )
-        for name, arch, width, layers, quality in TIERS:
-            cfg = reduced(
-                arch, d_model=width, n_layers=layers,
-                n_heads=4, n_kv_heads=2, head_dim=width // 4,
-            )
-            params = T.init_params(cfg, jax.random.key(seed))
-            engine.register(Variant(name, cfg, params, quality))
-        return engine
-    # With --replicas > 1 (or --shard-zoo / --transport) the remote tier
-    # becomes a replicated cluster behind the same execution protocol; the
-    # hedge tier stays the device-side singleton outside the pool.
-    backend = None
-    if replicas > 1 or shard_zoo or transport != "none":
-        slices = (
-            shard_slices([t[0] for t in TIERS], replicas)
-            if shard_zoo
-            else None
-        )
-
-        def make_replica():
-            if transport == "none":
-                return JitBackend(max_len)
-            # inline: same process, but with the transport's fault surface
-            # (kill/inject); process: a real spawned worker per replica.
-            return ProcessTransportBackend(
-                functools.partial(_jit_backend_factory, max_len),
-                mode=transport, max_len=max_len,
+    else:
+        # With --replicas > 1 (or --shard-zoo / --transport) the remote
+        # tier becomes a replicated cluster behind the same execution
+        # protocol; the hedge tier stays the device-side singleton outside
+        # the pool.
+        if replicas > 1 or shard_zoo or transport != "none":
+            slices = (
+                shard_slices([t[0] for t in tiers], replicas)
+                if shard_zoo
+                else None
             )
 
-        backend = ClusterBackend(
-            [make_replica() for _ in range(replicas)],
-            router=router, slices=slices, seed=seed, specs=specs,
+            def make_replica(i):
+                if transport == "none":
+                    return _jit_backend_factory(max_len, i)
+                # inline: same process, but with the transport's fault
+                # surface (kill/inject); process: a real spawned worker per
+                # replica.
+                return ProcessTransportBackend(
+                    functools.partial(_jit_backend_factory, max_len, i),
+                    mode=transport, max_len=max_len,
+                )
+
+            backend = ClusterBackend(
+                [make_replica(i) for i in range(replicas)],
+                router=router, slices=slices, seed=seed, specs=specs,
+            )
+        engine = ServingEngine(
+            max_len=max_len, backend=backend, hedge_backend=hedge,
+            dispatch=dispatch,
         )
-    engine = ServingEngine(
-        max_len=max_len, backend=backend, hedge_backend=hedge,
-        dispatch=dispatch,
-    )
-    for name, arch, width, layers, quality in TIERS:
-        cfg = reduced(
-            arch, d_model=width, n_layers=layers,
-            n_heads=4, n_kv_heads=2, head_dim=width // 4,
-        )
-        params = T.init_params(cfg, jax.random.key(seed))
+    # Jitted, each leaf is drawn straight into its dtype: eager init holds
+    # float32 temporaries of the largest leaf (2 x 3.2 GB for phi3-mini's
+    # stacked MLP) beside the weights built so far.
+    init = jax.jit(T.init_params, static_argnums=0)
+    for name, cfg, quality in tiers:
+        params = init(cfg, jax.random.key(seed))
         engine.register(Variant(name, cfg, params, quality))
     return engine
 
@@ -323,6 +374,9 @@ def main(argv=None):
                     "exposition of every counter/gauge/histogram to PATH")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    if args.transport == "process" and jax.default_backend() == "tpu":
+        ap.error(PROCESS_ON_TPU)
+    use_compile_cache()
     tenants = None
     if args.tenants:
         try:
@@ -456,16 +510,7 @@ def main(argv=None):
             f"compiled executables={compiles_after_warmup} (fixed from here)"
         )
         if measured:
-            # Pre-warm the hedge tier at every pow2 tick shape it can see:
-            # its first inline compile otherwise burns real wall-clock SLA
-            # budget mid-race and spuriously releases hedged slots.
-            N = 1
-            while N <= geometry.n_slots:
-                engine.hedge_backend.run_batch(
-                    engine.hedge_backend.hedge_name,
-                    np.zeros((N, args.prompt), np.int32), args.gen,
-                )
-                N *= 2
+            prewarm_hedge(engine, geometry.n_slots, args.prompt, args.gen)
 
     sched = MDInferenceScheduler(
         registry, ondevice, SchedulerConfig(t_sla_ms=args.sla, seed=args.seed)

@@ -19,8 +19,13 @@ __all__ = [
 
 
 def truncated_normal_init(key, shape, dtype, scale: float):
-    """He-style truncated normal, stddev = scale / sqrt(fan_in)."""
-    fan_in = shape[0] if len(shape) >= 2 else shape[-1]
+    """He-style truncated normal, stddev = scale / sqrt(fan_in).
+
+    The fan-in is the second-to-last dim: a kernel is ``(..., in, out)``,
+    whether it is one layer's ``(in, out)``, a per-expert ``(E, in, out)``
+    or a scan-stacked ``(layers, in, out)``.
+    """
+    fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
     std = scale / np.sqrt(max(fan_in, 1))
     return (jax.random.truncated_normal(key, -2.0, 2.0, shape) * std).astype(dtype)
 

@@ -20,6 +20,7 @@ charged to requests or folded into live latency profiles.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import threading
 import time
 from typing import Dict, Optional, Tuple
@@ -47,6 +48,7 @@ __all__ = [
     "OnDeviceBackend",
     "ContinuousBatchingBackend",
     "build_hedge_variant",
+    "continuous_step_programs",
 ]
 
 
@@ -337,16 +339,26 @@ class JitBackend(ExecutionBackend):
     ``max_len`` defaults to :data:`~repro.configs.mdinference_zoo.SERVING_GEOMETRY`
     — the zoo recipe is the single source of truth for cache geometry across
     all tiers (the historical hardcoded 256 lives there now).
+
+    ``device`` pins the replica to one device: :meth:`register` places each
+    variant's params there and every input follows, so the replicas of a
+    cluster compute on their own chips.  ``None`` keeps JAX's default
+    device.
     """
 
-    def __init__(self, max_len: Optional[int] = None):
+    def __init__(self, max_len: Optional[int] = None, device=None):
         super().__init__()
         self.max_len = SERVING_GEOMETRY.max_len if max_len is None else max_len
+        self.device = device
         self._prefill = {}
         self._decode = {}
 
     def register(self, v: Variant) -> None:
         cfg = v.cfg
+        if self.device is not None:
+            v = dataclasses.replace(
+                v, params=jax.device_put(v.params, self.device)
+            )
         self.variants[v.name] = v
 
         @jax.jit
@@ -362,7 +374,7 @@ class JitBackend(ExecutionBackend):
 
     def generate(self, name, tokens, n_steps, greedy=True):
         v = self.variants[name]
-        tokens = jnp.asarray(tokens, jnp.int32)
+        tokens = jax.device_put(np.asarray(tokens, np.int32), self.device)
         B, S = tokens.shape
         if n_steps <= 0:
             return np.zeros((B, 0), dtype=np.int32), 0.0
@@ -372,7 +384,7 @@ class JitBackend(ExecutionBackend):
         tok = jnp.argmax(logits, -1).astype(jnp.int32)
         for i in range(n_steps):
             out.append(tok)
-            pos = jnp.full((B,), S + i, jnp.int32)
+            pos = jax.device_put(np.full((B,), S + i, np.int32), self.device)
             logits, cache = self._decode[name](v.params, cache, tok, pos)
             tok = jnp.argmax(logits, -1).astype(jnp.int32)
         jax.block_until_ready(logits)
@@ -523,6 +535,46 @@ class _SlotRuntime:
     pos: int  # its absolute position (== tokens fed so far)
 
 
+def continuous_step_programs(cfg: ModelConfig, geometry: ServingGeometry):
+    """The continuous tier's fixed-shape jitted entry points.
+
+    Returns ``(prefill_fn, graft_fn, decode_fn)``.  ``prefill_fn`` holds
+    exactly one executable per ladder batch size after warmup and
+    ``decode_fn`` a single ``n_slots``-row one, so no request shape outside
+    the ladder ever reaches XLA.
+    """
+    g = geometry
+
+    @jax.jit
+    def prefill_fn(params, tokens, lengths):
+        cache, logits = T.prefill_ragged(
+            cfg, params, {"tokens": tokens}, lengths,
+            max_len=g.prompt_width,
+        )
+        return cache, jnp.argmax(logits, -1).astype(jnp.int32)
+
+    # The pool is donated: at published widths the graft otherwise holds
+    # the pool three times (input, output and a scatter temporary), which
+    # does not fit one 16 GB chip beside the weights.
+    @functools.partial(jax.jit, donate_argnums=0)
+    def graft_fn(pool, prefill_cache, tables):
+        # Batched: all rows of the chunk graft in one dispatch (one
+        # compile per ladder batch size, like prefill).  Padded rows
+        # carry an all-trash table.
+        return T.graft_prefill_batch(
+            cfg, pool, prefill_cache, tables, g.page_size
+        )
+
+    @jax.jit
+    def decode_fn(params, pool, tables, token, pos):
+        logits, pool = T.paged_decode_step(
+            cfg, params, pool, tables, token, pos, g.page_size
+        )
+        return jnp.argmax(logits, -1).astype(jnp.int32), pool
+
+    return prefill_fn, graft_fn, decode_fn
+
+
 class _ContinuousEngine:
     """Per-variant compiled entry points + slot bookkeeping."""
 
@@ -544,37 +596,9 @@ class _ContinuousEngine:
         self.slot_rt: Dict[int, _SlotRuntime] = {}
         self.warmed = False
 
-        # The fixed-shape entry points.  ``prefill`` is one jit object whose
-        # cache holds exactly one entry per ladder batch size after warmup;
-        # ``decode`` is a single (n_slots)-shaped executable.  No request
-        # shape outside the ladder ever reaches XLA.
-        @jax.jit
-        def prefill_fn(params, tokens, lengths):
-            cache, logits = T.prefill_ragged(
-                cfg, params, {"tokens": tokens}, lengths,
-                max_len=g.prompt_width,
-            )
-            return cache, jnp.argmax(logits, -1).astype(jnp.int32)
-
-        @jax.jit
-        def graft_fn(pool, prefill_cache, tables):
-            # Batched: all rows of the chunk graft in one dispatch (one
-            # compile per ladder batch size, like prefill).  Padded rows
-            # carry an all-trash table.
-            return T.graft_prefill_batch(
-                cfg, pool, prefill_cache, tables, g.page_size
-            )
-
-        @jax.jit
-        def decode_fn(params, pool, tables, token, pos):
-            logits, pool = T.paged_decode_step(
-                cfg, params, pool, tables, token, pos, g.page_size
-            )
-            return jnp.argmax(logits, -1).astype(jnp.int32), pool
-
-        self.prefill_fn = prefill_fn
-        self.graft_fn = graft_fn
-        self.decode_fn = decode_fn
+        self.prefill_fn, self.graft_fn, self.decode_fn = (
+            continuous_step_programs(cfg, geometry)
+        )
 
     @property
     def compile_count(self) -> int:
@@ -776,6 +800,9 @@ class ContinuousBatchingBackend(ExecutionBackend):
             for r, slot in enumerate(slots):
                 tables[r] = eng.cache_mgr.page_table(slot.index)
             eng.pool = eng.graft_fn(eng.pool, pcache, jnp.asarray(tables))
+            # Free the chunk's dense cache now, not when the next chunk
+            # replaces it: acquiring that chunk's slots may run decode steps.
+            del pcache
             for r, slot in enumerate(slots):
                 row = row0 + r
                 eng.cache_mgr.commit_graft(slot.index)

@@ -43,18 +43,28 @@ import threading
 import time
 from typing import Callable, Dict, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.serving.backend import BatchHandle, ExecutionBackend, Variant
 from repro.serving.transport_worker import worker_main
 
 __all__ = [
+    "PROCESS_ON_TPU",
     "TransportError",
     "ReplicaDied",
     "RemoteExecutionError",
     "FailedBatchHandle",
     "ProcessTransportBackend",
 ]
+
+
+PROCESS_ON_TPU = (
+    "process transport is CPU-only: a TPU chip belongs to the one process "
+    "that opened it, so a spawned replica worker cannot reach it. Serve "
+    "replicas in this process instead (transport 'none' or 'inline'), "
+    "one per device."
+)
 
 
 class TransportError(RuntimeError):
@@ -125,6 +135,8 @@ class ProcessTransportBackend(ExecutionBackend):
     ):
         if mode not in ("process", "inline"):
             raise ValueError(f"mode must be 'process' or 'inline', got {mode!r}")
+        if mode == "process" and jax.default_backend() == "tpu":
+            raise RuntimeError(PROCESS_ON_TPU)
         super().__init__()
         self.factory = factory
         self.mode = mode

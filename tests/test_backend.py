@@ -270,3 +270,20 @@ def test_build_hedge_variant_is_tiny():
     assert v.cfg.d_model == ONDEVICE_HEDGE.d_model
     assert v.cfg.n_layers == ONDEVICE_HEDGE.n_layers
     assert v.quality == ONDEVICE_HEDGE.quality
+
+
+def test_jit_backend_holds_params_on_its_device():
+    device = jax.devices()[0]
+    placed = JitBackend(MAX_LEN, device=device)
+    default = JitBackend(MAX_LEN)
+    v = _tiny_variant("small", 32, 40.0)
+    placed.register(v)
+    default.register(v)
+    params = placed.variants["small"].params
+    assert {d for leaf in jax.tree.leaves(params) for d in leaf.devices()} == {
+        device
+    }
+    prompts = np.arange(2 * PROMPT, dtype=np.int32).reshape(2, PROMPT) % 64
+    out, _ = placed.generate("small", prompts, GEN)
+    want, _ = default.generate("small", prompts, GEN)
+    np.testing.assert_array_equal(out, want)

@@ -7,6 +7,7 @@ and the spawns are cheap enough for tier-1 CI.
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -80,6 +81,18 @@ def test_inline_kill_then_restart():
     assert t.alive
     out, _ = t.run_batch("m", np.array([[1, 0]]), 2)
     np.testing.assert_array_equal(out, expected_tokens(np.array([[1, 0]]), 2))
+
+
+def test_process_mode_refuses_on_a_tpu(monkeypatch):
+    # A chip belongs to the one process that opened it: a spawned worker
+    # could never reach it.  Inline replicas stay in this process.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="CPU-only"):
+        ProcessTransportBackend(StubWorkerBackend, mode="process")
+    t = ProcessTransportBackend(StubWorkerBackend, mode="inline")
+    t.register(StubVariant("m"))
+    out, _ = t.run_batch("m", np.array([[3], [7]]), 2)
+    np.testing.assert_array_equal(out, expected_tokens([[3], [7]], 2))
 
 
 def test_inject_failures_rejected_in_process_mode():
