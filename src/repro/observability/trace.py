@@ -14,10 +14,18 @@ Span taxonomy (producers across the stack):
   tenant lane's track), ``queued`` (submit → tick claim), ``remote`` /
   ``ondevice`` tier legs (dispatch → done wall stamps, on the serving
   replica's track), and instants: ``scheduled``, ``ttft``,
-  ``stream.token``, ``requeue``, ``resolve`` / ``shed`` / ``cancel``
-  (exactly one terminal instant per request — the conservation check).
-* per tick — ``tick`` on the ``loop`` track, plus ``batch:<variant>``
-  group spans on each replica's track.
+  ``requeue``, ``resolve`` / ``shed`` / ``cancel`` (exactly one
+  terminal instant per request — the conservation check).  Per-token
+  stamps ride ``StreamChunk.wall_ms``, not spans.
+* per tick — ``admission.take`` (a take that took or shed work) and
+  ``tick`` on the ``loop`` track, ``policy.decide`` under the tick,
+  ``batch:<variant>`` group spans on each replica's track, and
+  ``loop.collect`` (a poll that collected ticks).
+* execution — ``continuous.submit`` with a ``continuous.prefill`` and a
+  ``continuous.graft`` per ladder chunk; ``decode.step`` with
+  ``decode.prepare`` / ``decode.run`` / ``decode.emit`` (only a poll
+  that steps active slots); ``hedge.run`` on the thread that runs the
+  on-device duplicate.
 * transport — ``transport.roundtrip`` with a nested ``worker.execute``
   reconstructed from the worker-side stamps that ride the completion
   message (see :mod:`repro.serving.transport`).

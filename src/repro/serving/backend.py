@@ -346,6 +346,11 @@ class JitBackend(ExecutionBackend):
     device.
     """
 
+    # Prefix of the jitted functions' names: a device trace names a run
+    # ``jit_<prefix>decode_fn``, so one tier's programs are told from
+    # another's by name.
+    program_prefix = ""
+
     def __init__(self, max_len: Optional[int] = None, device=None):
         super().__init__()
         self.max_len = SERVING_GEOMETRY.max_len if max_len is None else max_len
@@ -361,16 +366,16 @@ class JitBackend(ExecutionBackend):
             )
         self.variants[v.name] = v
 
-        @jax.jit
         def prefill_fn(params, tokens):
             return T.prefill(cfg, params, {"tokens": tokens}, max_len=self.max_len)
 
-        @jax.jit
         def decode_fn(params, cache, token, pos):
             return T.decode_step(cfg, params, cache, token, pos)
 
-        self._prefill[v.name] = prefill_fn
-        self._decode[v.name] = decode_fn
+        for fn in (prefill_fn, decode_fn):
+            fn.__name__ = fn.__qualname__ = self.program_prefix + fn.__name__
+        self._prefill[v.name] = jax.jit(prefill_fn)
+        self._decode[v.name] = jax.jit(decode_fn)
 
     def generate(self, name, tokens, n_steps, greedy=True):
         v = self.variants[name]
@@ -408,7 +413,10 @@ class OnDeviceBackend(JitBackend):
     finish within any reasonable SLA.  :meth:`hedge` runs the duplicate
     batch and returns measured wall time — the primary input to
     :meth:`repro.serving.scheduler.MDInferenceScheduler.resolve_chunk`.
+    Its programs are named ``hedge_prefill_fn`` / ``hedge_decode_fn``.
     """
+
+    program_prefix = "hedge_"
 
     def __init__(self, variant: Variant, max_len: Optional[int] = None):
         super().__init__(max_len)
@@ -431,6 +439,22 @@ class OnDeviceBackend(JitBackend):
             f"({self.hedge_name!r}); register remote variants on the "
             "primary backend instead"
         )
+
+    def generate(self, name, tokens, n_steps, greedy=True):
+        """:meth:`JitBackend.generate`, as a ``hedge.run`` span on the
+        thread that runs it (nested under the ambient dispatch span)."""
+        if self._obs is None:
+            return super().generate(name, tokens, n_steps, greedy)
+        tracer = self._obs.tracer
+        with tracer.span(
+            "hedge.run",
+            parent=tracer.ambient_id(),
+            cat="hedge",
+            track=self._obs_track,
+            rows=int(np.shape(tokens)[0]),
+            steps=int(n_steps),
+        ):
+            return super().generate(name, tokens, n_steps, greedy)
 
     def hedge(self, batch: np.ndarray, n_steps: int) -> Tuple[np.ndarray, float]:
         """Run the duplicate batch on the hedge variant (warm-once, timed)."""
@@ -753,7 +777,25 @@ class ContinuousBatchingBackend(ExecutionBackend):
         ``on_token(row, token, wall_ms)`` fires per emitted token — the
         first token at graft (the same wall stamp as ``ttft_wall_ms``),
         every later token from the decode pump — always *before* the row
-        completes, under both dispatch modes."""
+        completes, under both dispatch modes.
+
+        Traced, the call is a ``continuous.submit`` span (under the
+        ambient dispatch span) holding one ``continuous.prefill`` and one
+        ``continuous.graft`` span per ladder chunk."""
+        if self._obs is None:
+            return self._submit(name, batch, n_steps, sync, on_token, None)
+        tracer = self._obs.tracer
+        with tracer.span(
+            "continuous.submit",
+            parent=tracer.ambient_id(),
+            cat="continuous",
+            track=self._obs_track,
+            variant=name,
+            rows=int(np.shape(batch)[0]),
+        ) as span:
+            return self._submit(name, batch, n_steps, sync, on_token, span)
+
+    def _submit(self, name, batch, n_steps, sync, on_token, span):
         g = self.geometry
         eng = self._engines[name]
         batch = np.asarray(batch, dtype=np.int32)
@@ -790,16 +832,21 @@ class ContinuousBatchingBackend(ExecutionBackend):
             slots = [
                 self._acquire_slot(eng, S, n_steps) for _ in range(n_real)
             ]
+            phase = self._phase("continuous.prefill", span,
+                                rows=n_real, padded=N)
             pcache, first = eng.prefill_fn(
                 params, jnp.asarray(chunk), jnp.asarray(lengths)
             )
             first = np.asarray(first)
+            self._end(phase)
             # One batched graft for the whole chunk: real rows through
             # their slots' tables, padded rows through all-trash tables.
             tables = np.zeros((N, g.pages_per_slot), dtype=np.int32)
             for r, slot in enumerate(slots):
                 tables[r] = eng.cache_mgr.page_table(slot.index)
+            phase = self._phase("continuous.graft", span, rows=n_real)
             eng.pool = eng.graft_fn(eng.pool, pcache, jnp.asarray(tables))
+            self._end(phase)
             # Free the chunk's dense cache now, not when the next chunk
             # replaces it: acquiring that chunk's slots may run decode steps.
             del pcache
@@ -857,21 +904,47 @@ class ContinuousBatchingBackend(ExecutionBackend):
     def _pump_engine(self, eng: _ContinuousEngine) -> bool:
         if not eng.slot_rt:
             return False
+        if self._obs is None:
+            self._step(eng, None)
+            return True
+        tracer = self._obs.tracer
+        stepped = sorted(eng.slot_rt)
+        with tracer.span(
+            "decode.step",
+            parent=tracer.ambient_id(),
+            cat="continuous",
+            track=self._obs_track,
+            variant=eng.variant.name,
+            active=len(stepped),
+            positions=[eng.slot_rt[s].pos for s in stepped],
+        ) as span:
+            self._step(eng, span)
+        return True
+
+    def _step(self, eng: _ContinuousEngine, span) -> None:
+        """One decode step of every active slot; traced (``span`` the
+        step's span) as ``decode.prepare``, ``decode.run`` and
+        ``decode.emit`` in turn."""
         g = self.geometry
+        phase = self._phase("decode.prepare", span)
         token = np.zeros((g.n_slots,), dtype=np.int32)
         pos = np.zeros((g.n_slots,), dtype=np.int32)
         for s, rt in eng.slot_rt.items():
             token[s] = rt.tok
             pos[s] = rt.pos
-        tables = eng.cache_mgr.page_tables()
-        next_tok, eng.pool = eng.decode_fn(
-            eng.variant.params,
-            eng.pool,
-            jnp.asarray(tables),
+        inputs = (
+            jnp.asarray(eng.cache_mgr.page_tables()),
             jnp.asarray(token),
             jnp.asarray(pos),
         )
+        self._end(phase)
+        phase = self._phase("decode.run", span)
+        next_tok, eng.pool = eng.decode_fn(
+            eng.variant.params, eng.pool, *inputs
+        )
         next_tok = np.asarray(next_tok)
+        self._end(phase)
+        phase = self._phase("decode.emit", span)
         now_wall = time.perf_counter() * 1e3
         for s in list(eng.slot_rt):
             rt = eng.slot_rt[s]
@@ -882,7 +955,21 @@ class ContinuousBatchingBackend(ExecutionBackend):
                 rt.handle.on_token(rt.row, rt.tok, now_wall)
             if len(rt.handle.emitted[rt.row]) >= rt.handle.n_steps:
                 self._retire_slot(eng, s, "resolved")
-        return True
+        self._end(phase)
+
+    def _phase(self, name: str, parent, **args):
+        """Open a child span of ``parent`` on this tier's track; ``None``
+        (nothing recorded) where ``parent`` is: the call is untraced."""
+        if parent is None:
+            return None
+        return self._obs.tracer.start(
+            name, parent=parent, cat="continuous", track=self._obs_track,
+            **args,
+        )
+
+    def _end(self, span) -> None:
+        if span is not None:
+            self._obs.tracer.end(span)
 
     # -- retirement / early release -------------------------------------------
     def _retire_slot(self, eng: _ContinuousEngine, slot: int,

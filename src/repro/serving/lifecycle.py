@@ -289,14 +289,6 @@ class InferenceFuture:
         self._chunks.append(
             StreamChunk(len(self._chunks), int(token), float(wall_ms))
         )
-        if self._tracer is not None:
-            self._tracer.instant(
-                "stream.token",
-                parent=self.span,
-                cat="stream",
-                t_ms=wall_ms,
-                index=len(self._chunks) - 1,
-            )
 
     @property
     def chunks(self) -> List[StreamChunk]:

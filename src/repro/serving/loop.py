@@ -36,6 +36,7 @@ is a thin shim over one sync-collected tick of this loop.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import itertools
 import time
@@ -488,6 +489,8 @@ class ServingLoop:
         # The admission queue hands one tick's work over atomically: a
         # submit() racing this tick from another thread lands in either
         # this chunk or a later one, never vanishes.
+        obs = self.observability
+        take_t0 = time.perf_counter() * 1e3 if obs is not None else None
         take = self.admission.take(
             now_ms,
             default_sla_ms=self.scheduler.cfg.t_sla_ms,
@@ -501,9 +504,20 @@ class ServingLoop:
             return None
         now_ms = take.now_ms
         self.now_ms = max(self.now_ms, now_ms)
-        obs = self.observability
         tick_span = None
         if obs is not None:
+            # Recorded only for a take that did something: the loop ticks
+            # far more often than work arrives.
+            obs.tracer.end(
+                obs.tracer.start(
+                    "admission.take",
+                    cat="loop",
+                    track="loop",
+                    t0_ms=take_t0,
+                    n_taken=len(take.chunk),
+                    n_shed=len(take.shed),
+                )
+            )
             tick_span = obs.tracer.start(
                 "tick",
                 cat="loop",
@@ -583,10 +597,18 @@ class ServingLoop:
             )
             t_sla = slas if np.any(slas != loop_sla) else loop_sla
             est = np.asarray([r.t_nw_est_ms for r in requests])
-            decision = self.scheduler.decide_batch(
-                est + queue_wait + (loop_sla - slas),
-                eligible=eligible,
-            )
+            with (
+                obs.tracer.span(
+                    "policy.decide", parent=tick_span, cat="loop",
+                    track="loop", rows=len(batch),
+                )
+                if obs is not None
+                else contextlib.nullcontext()
+            ):
+                decision = self.scheduler.decide_batch(
+                    est + queue_wait + (loop_sla - slas),
+                    eligible=eligible,
+                )
 
             # Dispatch every batch of the tick before waiting on any of
             # them: the remote variant groups and the hedged rows'
@@ -756,6 +778,8 @@ class ServingLoop:
         pump = getattr(self.backend, "pump", None)
         if pump is not None:
             pump()
+        obs = self.observability
+        t0 = time.perf_counter() * 1e3 if obs is not None else None
         for t in self._inflight:
             self._release_hedge_wins(t)
         # Evaluate poll() once per tick: a batch finishing between two
@@ -763,7 +787,17 @@ class ServingLoop:
         ready = {id(t): t.poll() for t in self._inflight}
         done = [t for t in self._inflight if ready[id(t)]]
         self._inflight = [t for t in self._inflight if not ready[id(t)]]
-        return [self._collect(t) for t in done]
+        results = [self._collect(t) for t in done]
+        if obs is not None and done:
+            # The release pass and the collections, recorded only for a
+            # poll that collected a tick.
+            obs.tracer.end(
+                obs.tracer.start(
+                    "loop.collect", cat="loop", track="loop", t0_ms=t0,
+                    ticks=len(done),
+                )
+            )
+        return results
 
     def _release_hedge_wins(self, tick: _InflightTick) -> None:
         """Recycle slots of hedged rows whose race is already decided.
