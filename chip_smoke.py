@@ -192,11 +192,16 @@ def decode_step_ms(engine, name: str, *, pos: int, steps: int = 16):
     token = jnp.zeros((g.n_slots,), jnp.int32)
     at = jnp.full((g.n_slots,), pos, jnp.int32)
     before = eng.compile_count
-    jax.block_until_ready(eng.decode_fn(params, eng.pool, tables, token, at))
+    # The pool is donated: each call hands back the one the next call takes.
+    _, eng.pool = jax.block_until_ready(
+        eng.decode_fn(params, eng.pool, tables, token, at)
+    )
     times = []
     for _ in range(steps):
         t0 = time.perf_counter()
-        jax.block_until_ready(eng.decode_fn(params, eng.pool, tables, token, at))
+        _, eng.pool = jax.block_until_ready(
+            eng.decode_fn(params, eng.pool, tables, token, at)
+        )
         times.append((time.perf_counter() - t0) * 1e3)
     require(eng.compile_count == before, "timing the decode step recompiled it")
     return times

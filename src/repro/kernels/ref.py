@@ -50,10 +50,11 @@ def decode_attention_paged_ref(q, k_pool, v_pool, page_tables, pos, *,
                                window=0, scale=None):
     """q: (B, NKV, G, D); pools: (P, NKV, page, D) — kernel layout."""
     B, NKV, G, D = q.shape
+    P, _, page, _ = k_pool.shape
     out = _paged_decode_ref(
         q.reshape(B, 1, NKV * G, D),
-        k_pool.transpose(0, 2, 1, 3),
-        v_pool.transpose(0, 2, 1, 3),
+        k_pool.transpose(0, 2, 1, 3).reshape(P, page, NKV * D),
+        v_pool.transpose(0, 2, 1, 3).reshape(P, page, NKV * D),
         page_tables,
         pos,
         window=window,

@@ -321,7 +321,8 @@ def decode_attention(q, k_cache, v_cache, slot_pos, pos, *, window: int = 0, sca
 
 
 def paged_decode_attention(
-    q, k_pool, v_pool, page_tables, pos, *, window: int = 0, scale=None
+    q, k_pool, v_pool, page_tables, pos, *, layer=None, window: int = 0,
+    scale=None
 ):
     """Single-step attention over a block-paged KV pool.
 
@@ -336,22 +337,26 @@ def paged_decode_attention(
 
     Args:
       q: (B, 1, NQ, HD) query for the new token.
-      k_pool, v_pool: (P, page, NKV, HD) physical page pools.
+      k_pool, v_pool: (P, page, NKV*HD) heads-merged page pools, or
+        (L, P, page, NKV*HD) stacked over layers with ``layer`` picking one.
       page_tables: (B, NB) int32 page ids per row.
       pos: (B,) absolute position of each row's query token.
+      layer: index into a stacked pool's leading axis.  The pages are
+        gathered at ``(layer, table)`` in one gather, so no layer is ever
+        sliced out of the stack.
       window: sliding window (0 = unlimited).
 
-    This is the runtime (pure-jnp) path; the Pallas TPU substrate with the
-    same table-indexed layout is ``repro.kernels.decode_attention.
+    This is the runtime (pure-jnp) path; the Pallas TPU substrate with a
+    table-indexed layout is ``repro.kernels.decode_attention.
     decode_attention_paged_fwd``.
     """
-    P, page, NKV, HD = k_pool.shape
+    HD = q.shape[-1]
+    page, F = k_pool.shape[-2:]
     B, NB = page_tables.shape
     S = NB * page
-    flat = page_tables[:, :, None] * page + jnp.arange(page)[None, None, :]
-    flat = flat.reshape(B, S)  # (B, S) indices into the flattened pool
-    k_dense = k_pool.reshape(P * page, NKV, HD)[flat]  # (B, S, NKV, HD)
-    v_dense = v_pool.reshape(P * page, NKV, HD)[flat]
+    at = (page_tables,) if layer is None else (layer, page_tables)
+    k_dense = k_pool[at].reshape(B, S, F // HD, HD)  # (B, S, NKV, HD)
+    v_dense = v_pool[at].reshape(B, S, F // HD, HD)
     slot_pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
     return decode_attention(
         q, k_dense, v_dense, slot_pos, pos, window=window, scale=scale

@@ -258,25 +258,25 @@ def _attention(cfg, p, x, ctx: SeqContext, kind: str, cache):
         )
         pos = ctx.positions[:, 0]  # (B,)
         if ctx.page_tables is not None:
-            # Block-paged decode (continuous batching): rows advance at
-            # *independent* positions, each writing into its own page-table
-            # slot of the shared pool.  The per-row scatter is fine here —
-            # this path serves the single-host continuous tier, where the
-            # pool is unsharded (the lockstep dynamic-update-slice below
-            # exists for the seq-sharded multi-pod caches).  Inactive rows
-            # carry pos=0 and an all-trash table, so their writes land in
-            # the reserved trash page.
-            page = ctx.page_size
-            P = cache["kp"].shape[0]
-            tbl = ctx.page_tables  # (B, NB)
-            flat_idx = (
-                tbl[jnp.arange(B), pos // page] * page + pos % page
-            )  # (B,)
-            kf = cache["kp"].reshape(P * page, nkv, hd).at[flat_idx].set(k[:, 0])
-            vf = cache["vp"].reshape(P * page, nkv, hd).at[flat_idx].set(v[:, 0])
-            kp = kf.reshape(P, page, nkv, hd)
-            vp = vf.reshape(P, page, nkv, hd)
-            out = paged_decode_attention(q, kp, vp, tbl, pos, window=window)
+            # Block-paged decode (continuous batching, `_run_paged_stack`):
+            # rows advance at *independent* positions.  ``cache`` holds the
+            # whole (stacked) pool and the ``layer`` to use; the B new rows
+            # go in with one scatter at (layer, page, offset) and the pages
+            # come out with one gather at (layer, table), so the pool is
+            # updated in place.  The per-row scatter is fine here: the pool
+            # is unsharded (the lockstep dynamic-update-slice below exists
+            # for the seq-sharded multi-pod caches).  Inactive rows carry
+            # pos=0 and an all-trash table, so their writes land in the
+            # reserved trash page.
+            page, tbl, layer = ctx.page_size, ctx.page_tables, cache["layer"]
+            at = (tbl[jnp.arange(B), pos // page], pos % page)
+            if layer is not None:
+                at = (layer, *at)
+            kp = cache["kp"].at[at].set(k.reshape(B, nkv * hd))
+            vp = cache["vp"].at[at].set(v.reshape(B, nkv * hd))
+            out = paged_decode_attention(
+                q, kp, vp, tbl, pos, layer=layer, window=window
+            )
             new_cache = {"kp": kp, "vp": vp}
             out = constrain(out.reshape(B, S, nq * hd), "batch", "seq", "heads")
             return out @ p["wo"], new_cache
@@ -594,13 +594,11 @@ def _run_stack(cfg, params, x, ctx: SeqContext, cache=None, collect_cache=False)
     return x, new_cache, aux
 
 
-def forward_hidden(cfg, params, batch_inputs, cache=None, decode=False, positions=None,
-                   page_tables=None, page_size=0):
+def forward_hidden(cfg, params, batch_inputs, cache=None, decode=False, positions=None):
     x, pos, prefix_len, _ = _embed_inputs(cfg, params, batch_inputs)
     if positions is not None:
         pos = positions
-    ctx = SeqContext(positions=pos, prefix_len=prefix_len, decode=decode,
-                     page_tables=page_tables, page_size=page_size)
+    ctx = SeqContext(positions=pos, prefix_len=prefix_len, decode=decode)
     x = constrain(x, "batch", "seq_act" if not decode else "seq", None)
     x, new_cache, aux = _run_stack(cfg, params, x, ctx, cache=cache)
     x = _norm(cfg, params["final_norm"], x)
@@ -727,15 +725,17 @@ def _paged_block_cache(cfg, kind, n_pages, page_size, dtype):
         raise ValueError(
             f"paged decode supports attention blocks only, got {kind!r}"
         )
-    return {
-        "kp": jnp.zeros((n_pages, page_size, cfg.n_kv_heads, cfg.head_dim), dtype),
-        "vp": jnp.zeros((n_pages, page_size, cfg.n_kv_heads, cfg.head_dim), dtype),
-    }
+    # Heads merged into the minor axis (phi3-mini: 3072 = 24 lanes): the
+    # chip then tiles the pool rows-minor, and a row is written in place.
+    shape = (n_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
+    return {"kp": jnp.zeros(shape, dtype), "vp": jnp.zeros(shape, dtype)}
 
 
 def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int):
     """Physical KV page pools for every attention layer (no batch dim —
-    rows share the pool through their page tables)."""
+    rows share the pool through their page tables): ``(L, P, page,
+    NKV*HD)`` per block of the period, ``(P, page, NKV*HD)`` per epilogue
+    block."""
     if not supports_paged_decode(cfg):
         raise ValueError(
             f"config {cfg.name!r} cannot use the paged decode path "
@@ -765,42 +765,20 @@ def graft_prefill(cfg, paged_cache, prefill_cache, row, page_table, page_size: i
     the trash page, positions between the row's real length and ``W`` are
     pad garbage that decode overwrites in place before the mask ever
     exposes them.  Fixed shapes throughout — one compile per prefill batch
-    size.
+    size.  The one-row case of :func:`graft_prefill_batch`.
     """
-    idx = jnp.arange(prefill_cache_width(prefill_cache))
-    flat_idx = page_table[idx // page_size] * page_size + idx % page_size
-
-    def graft_leaves(pool, pre):
-        # pool: (*lead, P, page, NKV, HD); pre: (*lead, B, W, NKV, HD)
-        def one(pool_leaf, pre_leaf):
-            P, page = pool_leaf.shape[-4], pool_leaf.shape[-3]
-            nkv, hd = pool_leaf.shape[-2], pool_leaf.shape[-1]
-            lead = pool_leaf.shape[:-4]
-            src = jnp.take(pre_leaf, row, axis=len(lead))  # (*lead, W, NKV, HD)
-            flat = pool_leaf.reshape(*lead, P * page, nkv, hd)
-            if lead:
-                flat = flat.at[:, flat_idx].set(src)
-            else:
-                flat = flat.at[flat_idx].set(src)
-            return flat.reshape(*lead, P, page, nkv, hd)
-
-        return one(pool, pre)
-
-    new_periods = tuple(
-        {
-            "kp": graft_leaves(pc["kp"], pf["k"]),
-            "vp": graft_leaves(pc["vp"], pf["v"]),
-        }
-        for pc, pf in zip(paged_cache["periods"], prefill_cache["periods"])
+    rows = jnp.asarray(row)[None]
+    one = {
+        "periods": jax.tree.map(
+            lambda a: jnp.take(a, rows, axis=1), prefill_cache["periods"]
+        ),
+        "epilogue": jax.tree.map(
+            lambda a: jnp.take(a, rows, axis=0), prefill_cache["epilogue"]
+        ),
+    }
+    return graft_prefill_batch(
+        cfg, paged_cache, one, jnp.asarray(page_table)[None], page_size
     )
-    new_epilogue = tuple(
-        {
-            "kp": graft_leaves(pc["kp"], pf["k"]),
-            "vp": graft_leaves(pc["vp"], pf["v"]),
-        }
-        for pc, pf in zip(paged_cache["epilogue"], prefill_cache["epilogue"])
-    )
-    return {"periods": new_periods, "epilogue": new_epilogue}
 
 
 def graft_prefill_batch(cfg, paged_cache, prefill_cache, page_tables,
@@ -820,17 +798,15 @@ def graft_prefill_batch(cfg, paged_cache, prefill_cache, page_tables,
     ).reshape(-1)  # (B*W,) flat pool positions
 
     def graft_leaves(pool_leaf, pre_leaf):
-        # pool: (*lead, P, page, NKV, HD); pre: (*lead, B, W, NKV, HD)
-        P, page = pool_leaf.shape[-4], pool_leaf.shape[-3]
-        nkv, hd = pool_leaf.shape[-2], pool_leaf.shape[-1]
-        lead = pool_leaf.shape[:-4]
-        src = pre_leaf.reshape(*lead, -1, nkv, hd)  # (*lead, B*W, NKV, HD)
-        flat = pool_leaf.reshape(*lead, P * page, nkv, hd)
+        # pool: (*lead, P, page, NKV*HD); pre: (*lead, B, W, NKV, HD)
+        *lead, P, page, F = pool_leaf.shape
+        src = pre_leaf.reshape(*lead, -1, F)  # (*lead, B*W, NKV*HD)
+        flat = pool_leaf.reshape(*lead, P * page, F)
         if lead:
             flat = flat.at[:, flat_idx].set(src)
         else:
             flat = flat.at[flat_idx].set(src)
-        return flat.reshape(*lead, P, page, nkv, hd)
+        return flat.reshape(pool_leaf.shape)
 
     new_periods = tuple(
         {
@@ -858,18 +834,63 @@ def prefill_cache_width(prefill_cache) -> int:
     raise ValueError("prefill cache has no attention layers")
 
 
+def _run_paged_stack(cfg, params, x, ctx: SeqContext, pool):
+    """The paged decode's layer loop.  Returns (x, new_pool).
+
+    The whole pool rides in the carry of the scan over periods, with the
+    period index in its ``xs``: each attention block scatters its rows into
+    the carried pool and gathers its pages straight from it (see the paged
+    branch of :func:`_attention`), so under a donated pool the step updates
+    it in place.  :func:`_run_stack` instead passes caches through the
+    scan's ``xs``/``ys``, which slices every layer out of the stack and
+    restacks it: right for the dense ring caches (sharded per row over
+    devices), a copy of the whole pool per step here.
+    """
+
+    def block(kind, p, x, layer_pool, layer):
+        cache = dict(layer_pool, layer=layer)
+        x, layer_pool, _ = apply_block(cfg, kind, p, x, ctx, cache)
+        return x, layer_pool
+
+    def period(carry, xs):
+        x, pools = carry
+        period_params, layer = xs
+        new_pools = []
+        for kind, p, layer_pool in zip(cfg.pattern, period_params, pools):
+            x, layer_pool = block(kind, p, x, layer_pool, layer)
+            new_pools.append(layer_pool)
+        # Weight-stationary decode boundary, as in _run_stack.
+        x = constrain(x, "batch", None, "embed_act")
+        return (x, tuple(new_pools)), None
+
+    periods = pool["periods"]
+    if cfg.n_periods > 0:
+        (x, periods), _ = jax.lax.scan(
+            period, (x, periods),
+            (params["periods"], jnp.arange(cfg.n_periods)),
+        )
+    epilogue = []
+    for kind, p, layer_pool in zip(cfg.epilogue, params["epilogue"],
+                                   pool["epilogue"]):
+        x, layer_pool = block(kind, p, x, layer_pool, None)
+        epilogue.append(layer_pool)
+    return x, {"periods": periods, "epilogue": tuple(epilogue)}
+
+
 def paged_decode_step(cfg, params, paged_cache, page_tables, token, pos,
                       page_size: int):
     """One decode step over the shared page pool.
 
     token/pos: (B,) int32 — per-row positions (rows need *not* be in
     lockstep; that is the point).  ``page_tables``: (B, NB) int32.
-    Inactive rows should carry pos=0 and an all-trash table.
+    Inactive rows should carry pos=0 and an all-trash table.  Returns
+    (logits, new pool); donate the pool to update it in place.
     """
-    inputs = {"tokens": token[:, None]}
-    x, new_cache, _ = forward_hidden(
-        cfg, params, inputs, cache=paged_cache, decode=True,
-        positions=pos[:, None], page_tables=page_tables, page_size=page_size,
-    )
+    x, _, _, _ = _embed_inputs(cfg, params, {"tokens": token[:, None]})
+    ctx = SeqContext(positions=pos[:, None], decode=True,
+                     page_tables=page_tables, page_size=page_size)
+    x = constrain(x, "batch", "seq", None)
+    x, new_cache = _run_paged_stack(cfg, params, x, ctx, paged_cache)
+    x = _norm(cfg, params["final_norm"], x)
     logits = _unembed(cfg, params, x)[:, 0]
     return logits, new_cache

@@ -589,7 +589,9 @@ def continuous_step_programs(cfg: ModelConfig, geometry: ServingGeometry):
             cfg, pool, prefill_cache, tables, g.page_size
         )
 
-    @jax.jit
+    # Donated too: the step writes each layer's new rows into the pool in
+    # place (T.paged_decode_step carries it whole through the layer loop).
+    @functools.partial(jax.jit, donate_argnums=1)
     def decode_fn(params, pool, tables, token, pos):
         logits, pool = T.paged_decode_step(
             cfg, params, pool, tables, token, pos, g.page_size

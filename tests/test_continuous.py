@@ -16,6 +16,7 @@ The PR's correctness contract, bottom-up:
   ``ttft_ms`` on completions, and the scheduler's mid-flight-join EWMA.
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -27,6 +28,7 @@ from repro.serving.backend import (
     JitBackend,
     OnDeviceBackend,
     Variant,
+    continuous_step_programs,
 )
 from repro.serving.block_cache import BlockPagedSlotCache, NoFreeSlot
 from repro.serving.engine import QueuedRequest, ServingEngine
@@ -215,6 +217,112 @@ def test_backend_max_len_defaults_to_geometry():
     assert JitBackend().max_len == SERVING_GEOMETRY.max_len
     assert JitBackend(max_len=48).max_len == 48
     assert OnDeviceBackend.from_zoo().max_len == SERVING_GEOMETRY.max_len
+
+
+# ---------------------------------------------------------------------------
+# The paged decode loop against the dense ring-cache decode.
+# ---------------------------------------------------------------------------
+STEPS = 16
+
+
+def _period_of_two_cfg():
+    """Two periods of (``local``, ``moe``) and a ``local`` epilogue block:
+    GQA, qk-norm, and a 20-position window, which the decode steps pass
+    (the graft takes a window no narrower than the prompt).  Capacity for
+    every routed token, so a row's experts do not depend on the rows
+    beside it."""
+    return reduced(
+        "olmoe-1b-7b", pattern=("local", "moe"), n_layers=5, window=20,
+        n_kv_heads=2, capacity_factor=2.0,
+    )
+
+
+@pytest.mark.parametrize("which", ["attn", "local-moe-epilogue"])
+def test_paged_decode_matches_dense_decode(variant, which):
+    """Rows at independent positions, beside an inactive trash-table row,
+    decode through the paged layer loop token for token as each row does
+    alone on the dense ring cache, with logits within the decode tests'
+    tolerance, over ``STEPS`` steps.  Rows 0 and 3 are grafted by
+    ``graft_prefill_batch``, row 2 by ``graft_prefill``."""
+    if which == "attn":
+        cfg, params = variant.cfg, variant.params
+    else:
+        cfg = _period_of_two_cfg()
+        params = T.init_params(cfg, jax.random.key(1))
+    page, width = 4, 16
+    lengths = np.array([5, 0, 16, 9], np.int32)  # row 1 inactive
+    active = np.flatnonzero(lengths)
+    prompts = np.random.default_rng(5).integers(
+        1, cfg.vocab_size, (len(lengths), width)
+    ).astype(np.int32)
+    prompts[np.arange(width)[None] >= lengths[:, None]] = 0
+    tol = dict(rtol=3e-4, atol=3e-4)
+
+    pages = (width + STEPS) // page
+    tables = np.zeros((len(lengths), pages), np.int32)
+    for i, r in enumerate(active):
+        tables[r] = 1 + i * pages + np.arange(pages)
+    pcache, logits = T.prefill_ragged(
+        cfg, params, {"tokens": jnp.asarray(prompts)},
+        jnp.asarray(lengths), max_len=width,
+    )
+    pool = T.init_paged_cache(cfg, 1 + len(active) * pages, page)
+    batch_tables = tables.copy()
+    batch_tables[2] = 0  # row 2 goes through graft_prefill instead
+    pool = T.graft_prefill_batch(
+        cfg, pool, pcache, jnp.asarray(batch_tables), page
+    )
+    pool = T.graft_prefill(cfg, pool, pcache, 2, jnp.asarray(tables[2]), page)
+    step = jax.jit(
+        lambda p, pool, tb, tok, pos: T.paged_decode_step(
+            cfg, p, pool, tb, tok, pos, page
+        ),
+        donate_argnums=1,
+    )
+
+    dense_step = jax.jit(
+        lambda p, cache, tok, pos: T.decode_step(cfg, p, cache, tok, pos)
+    )
+    dense = {}
+    for r in active:
+        n = int(lengths[r])
+        cache, dl = T.prefill(
+            cfg, params, {"tokens": jnp.asarray(prompts[r : r + 1, :n])},
+            max_len=n + STEPS,
+        )
+        np.testing.assert_allclose(logits[r], dl[0], **tol)
+        dense[r] = (cache, dl)
+
+    for i in range(STEPS):
+        tok = np.where(lengths > 0, np.argmax(logits, -1), 0).astype(np.int32)
+        pos = np.where(lengths > 0, lengths + i, 0).astype(np.int32)
+        logits, pool = step(
+            params, pool, jnp.asarray(tables), jnp.asarray(tok),
+            jnp.asarray(pos),
+        )
+        for r in active:
+            cache, dl = dense[r]
+            dtok = jnp.argmax(dl, -1).astype(jnp.int32)
+            assert int(dtok[0]) == tok[r], f"row {r} step {i}"
+            dl, cache = dense_step(
+                params, cache, dtok, jnp.asarray([pos[r]], jnp.int32)
+            )
+            np.testing.assert_allclose(
+                logits[r], dl[0], err_msg=f"row {r} step {i}", **tol
+            )
+            dense[r] = (cache, dl)
+
+
+def test_decode_fn_donates_the_pool(variant):
+    """The served decode program updates the pool in place: the pool it
+    was given is gone after the call, and the one it returns is live."""
+    _, _, decode_fn = continuous_step_programs(variant.cfg, GEO)
+    pool = T.init_paged_cache(variant.cfg, GEO.total_pages, GEO.page_size)
+    tables = jnp.zeros((GEO.n_slots, GEO.pages_per_slot), jnp.int32)
+    slots = jnp.zeros((GEO.n_slots,), jnp.int32)
+    _, new_pool = decode_fn(variant.params, pool, tables, slots, slots)
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(pool))
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(new_pool))
 
 
 # ---------------------------------------------------------------------------

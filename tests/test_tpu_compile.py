@@ -152,6 +152,19 @@ def test_continuous_decode_compiles(one_chip, phi3):
         slots, slots,
     ).compile()
     assert _live_bytes(compiled) < HBM_BYTES
+    m = compiled.memory_analysis()
+    # The pool is donated and updated in place: the step's output reuses
+    # the whole pool, and it holds no layer's pages (K+V) as a temporary —
+    # no slice, relayout or restack of the pool per layer.
+    pool_bytes = sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(pool)
+    )
+    assert m.alias_size_in_bytes >= pool_bytes
+    layer_kv_bytes = sum(
+        leaf.size // leaf.shape[0] * leaf.dtype.itemsize
+        for leaf in jax.tree.leaves(pool["periods"])
+    )
+    assert m.temp_size_in_bytes < layer_kv_bytes
 
 
 # ---------------------------------------------------------------------------
