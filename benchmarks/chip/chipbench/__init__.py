@@ -1,6 +1,7 @@
 """The chip benchmark of the MDInference serving stack.
 
-Everything that belongs to one configuration, traffic mix or per-layer
-metric lives in a file of its own under ``configs/``, ``traffic/`` and
-``metrics/``; this package holds the general parts that read them.
+Everything that belongs to one configuration, traffic mix, per-layer
+metric or model architecture lives in a file of its own under
+``configs/``, ``traffic/``, ``metrics/`` and ``chipbench/families/``; the
+rest of this package holds the general parts that read them.
 """

@@ -2,12 +2,13 @@
 
 These count what the algorithm requires, not what the program happens to
 execute: matrix products at two operations per multiply-add, causal
-attention over the real positions only, weights read once per program
-run (an embedding table only as far as it is looked up), and the keys
-and values of real positions only (no padded rows, no
-inactive decode slots, no trash page).  Embedding lookups, norms and
-activations are left out: they are below 1% of the operations and bytes
-at the served widths.
+attention over the real positions only, the weights that a program run's
+tokens reach read once per run (an embedding table only as far as it is
+looked up), and the keys and values of real positions only (no padded
+rows, no inactive decode slots, no trash page).  Embedding lookups, norms
+and activations are left out: they are below 1% of the operations and
+bytes at the served widths.  What depends on the architecture comes from
+the model's family (``chipbench/families/``).
 """
 from __future__ import annotations
 
@@ -24,52 +25,55 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def _dense_flops(m: Model) -> int:
-    """Matrix-product operations per token, outside attention scores."""
-    d, hd, nq, nkv, f = m.d_model, m.head_dim, m.n_heads, m.n_kv_heads, m.d_ff
-    per_layer = 2 * d * (nq + 2 * nkv) * hd + 2 * nq * hd * d + 3 * 2 * d * f
-    return m.n_layers * per_layer + 2 * d * m.vocab_size
-
-
-def _attn_flops(m: Model, keys: int) -> int:
-    """Scores and weighted values of one query over ``keys`` positions."""
-    return m.n_layers * 4 * m.n_heads * m.head_dim * keys
+def _family(m: Model):
+    return spec.family(m.kind)
 
 
 def step_weight_bytes(m: Model) -> int:
-    """Weight bytes one forward run reads: all of them, except that an
-    untied embedding table gives only the rows of the tokens looked up
-    (left out, like the other lookups)."""
+    """Weight bytes of a forward run that reaches every weight: all of
+    them, except that an untied embedding table gives only the rows of the
+    tokens looked up (left out, like the other lookups)."""
     table = 0 if m.tie_embeddings else m.vocab_size * m.d_model * m.dtype_bytes
     return weight_bytes(m) - table
 
 
+def least_weight_bytes(m: Model) -> int:
+    """The least weight bytes any one run of ``m`` reads (all of
+    :func:`step_weight_bytes` where every run reads every weight)."""
+    return _family(m).least_weight_bytes(m)
+
+
 def kv_bytes_per_token(m: Model) -> int:
-    return m.n_layers * 2 * m.n_kv_heads * m.head_dim * m.dtype_bytes
+    return _family(m).kv_bytes_per_token(m)
 
 
 def prefill_flops(m: Model, tokens: int) -> int:
     """One causal prompt of ``tokens`` positions."""
-    return tokens * _dense_flops(m) + _attn_flops(m, tokens * (tokens + 1) // 2)
+    f = _family(m)
+    return tokens * f.token_flops(m) + f.key_flops(m) * (tokens * (tokens + 1) // 2)
 
 
 def decode_flops(m: Model, position: int) -> int:
     """One generated token whose query sits at ``position`` (0-based)."""
-    return _dense_flops(m) + _attn_flops(m, position + 1)
+    f = _family(m)
+    return f.token_flops(m) + f.key_flops(m) * (position + 1)
 
 
 def prefill_bytes(m: Model, rows: int, tokens: int) -> int:
-    """A prefill run of ``rows`` real prompts: the weights once and the
-    keys and values written for every real position."""
-    return step_weight_bytes(m) + rows * tokens * kv_bytes_per_token(m)
+    """A prefill run of ``rows`` real prompts: the weights those tokens
+    reach and the keys and values written for every real position."""
+    return (_family(m).run_weight_bytes(m, rows * tokens)
+            + rows * tokens * kv_bytes_per_token(m))
 
 
 def decode_bytes(m: Model, positions) -> int:
     """A decode run whose active slots sit at ``positions``: the weights
-    once, each slot's keys and values read over its real positions and
-    the new ones written."""
+    their tokens reach, each slot's keys and values read over its real
+    positions and the new ones written."""
+    positions = list(positions)
     kv = kv_bytes_per_token(m)
-    return step_weight_bytes(m) + sum((p + 1) * kv for p in positions)
+    return (_family(m).run_weight_bytes(m, len(positions))
+            + sum((p + 1) * kv for p in positions))
 
 
 def least_seconds(flops: float, bytes_: float, peak: dict) -> float:

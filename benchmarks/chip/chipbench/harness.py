@@ -37,23 +37,9 @@ def _pow2_upto(n: int) -> List[int]:
     return out
 
 
-def program_model(m: Model):
-    """The program's ``ModelConfig`` for a published model."""
-    from repro.models.config import ModelConfig
-
-    return ModelConfig(
-        name=m.name, family="dense", n_layers=m.n_layers, d_model=m.d_model,
-        n_heads=m.n_heads, n_kv_heads=m.n_kv_heads, head_dim=m.head_dim,
-        d_ff=m.d_ff, vocab_size=m.vocab_size, pattern=("attn",),
-        mlp_type=m.mlp, rope_theta=m.rope_theta, norm_eps=m.norm_eps,
-        tie_embeddings=m.tie_embeddings, emb_scale=m.emb_scale,
-        norm_offset=m.norm_offset, dtype=m.dtype,
-    )
-
-
 def _same_model(program_cfg, m: Model) -> List[str]:
     """Fields in which the program's model differs from ``m``."""
-    want = program_model(m)
+    want = spec.family(m.kind).program_model(m)
     keys = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
             "d_ff", "vocab_size", "pattern", "mlp_type", "rope_theta",
             "norm_eps", "tie_embeddings", "emb_scale", "norm_offset", "dtype",
@@ -92,7 +78,8 @@ def build(cfg: spec.Config, mix: dict, seed: int, t_start: float) -> Served:
         max_len=geometry.max_len, seed=seed, dispatch=sv["dispatch"],
         replicas=int(sv.get("replicas", 1)), router=sv.get("router", "round_robin"),
         geometry=geometry,
-        tiers=((cfg.name, program_model(cfg.remote), cfg.remote_quality),),
+        tiers=((cfg.name, spec.family(cfg.remote.kind).program_model(cfg.remote),
+                cfg.remote_quality),),
     )
     hedge = engine.hedge_backend
     hv = hedge.variants[hedge.hedge_name]

@@ -27,10 +27,11 @@ def p90(values) -> Optional[float]:
 
 
 def _weights_floor_s(view) -> float:
-    """A run of the remote model reads all of its weights, so a run shorter
-    than their bytes over the chip's bandwidth is not the remote model's
-    (the hedge's programs share its names and take microseconds)."""
-    return counts.step_weight_bytes(view.cfg.remote) / view.peak["hbm_bytes_per_s"]
+    """Every run of the remote model reads at least the least weight bytes
+    its family gives (all of its weights, for a dense model), so a run
+    shorter than those bytes over the chip's bandwidth is not the remote
+    model's (the hedge's programs take microseconds)."""
+    return counts.least_weight_bytes(view.cfg.remote) / view.peak["hbm_bytes_per_s"]
 
 
 def served_runs(view, function: str) -> List[float]:

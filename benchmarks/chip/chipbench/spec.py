@@ -2,15 +2,20 @@
 
 ``BENCHMARK.json`` names each cell's configuration and traffic mix;
 ``configs/<name>.json`` and ``traffic/<name>.json`` hold them, and
-``metrics/<name>.py`` reads each per-layer metric.  Nothing here imports
-the program under test.
+``metrics/<name>.py`` reads each per-layer metric; ``chipbench/families/
+<model_type>.py`` holds all that depends on a model's architecture.
+Nothing here imports the program under test.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import pathlib
-from typing import Optional
+import re
+import sys
+from types import ModuleType
+from typing import ClassVar, Optional
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parents[1]
@@ -51,11 +56,12 @@ def metric_file(name: str) -> pathlib.Path:
 class Model:
     """The shapes of one served model, read from its published config.
 
-    Only what the reference, the weight recipe and the operation counts
-    need.  ``kind`` is the block family: ``phi3`` (pre-norm attention plus
-    a SwiGLU MLP) or ``gemma`` (the same with embeddings scaled by
-    sqrt(hidden), RMSNorm weights taken as 1 + w, a tanh-GELU gated MLP and
-    a head tied to the embeddings).
+    ``kind`` is the published ``model_type``; it names the family module,
+    ``chipbench/families/<kind>.py``, that parses the config and holds the
+    model's weight layout, plain reference, operation counts and the
+    program's ``ModelConfig``.  A family may subclass this with fields of
+    its own; it stays frozen and hashable, since the reference is jitted
+    with the model as a static argument.
     """
 
     name: str
@@ -73,55 +79,40 @@ class Model:
     dtype: str
     sliding_window: Optional[int] = None
 
-    @property
-    def emb_scale(self) -> bool:
-        return self.kind == "gemma"
-
-    @property
-    def norm_offset(self) -> bool:
-        return self.kind == "gemma"
-
-    @property
-    def mlp(self) -> str:
-        return "geglu" if self.kind == "gemma" else "swiglu"
+    # RMSNorm weights taken as 1 + w, so that the weight recipe draws them
+    # as zeros; a family sets it for all of its models.
+    norm_offset: ClassVar[bool] = False
 
     @property
     def dtype_bytes(self) -> int:
         return {"bfloat16": 2, "float32": 4}[self.dtype]
 
 
-# The block families the reference knows, by model_type, with their MLP
-# activation.
-_ACTS = {"phi3": "silu", "gemma": "gelu_pytorch_tanh"}
+def family_file(model_type: str) -> pathlib.Path:
+    return BENCH_DIR / "chipbench" / "families" / f"{model_type}.py"
+
+
+def family(model_type: str) -> ModuleType:
+    """The family module of a published ``model_type``, loaded once from
+    :func:`family_file`; an unknown ``model_type`` is refused."""
+    path = family_file(model_type)
+    if not re.fullmatch(r"[a-z0-9][a-z0-9_-]*", model_type) or not path.is_file():
+        raise ValueError(f"model_type {model_type!r} has no reference here: "
+                         f"{path} would define it")
+    name = f"chipbench.families.{model_type}"
+    mod = sys.modules.get(name)
+    if mod is None or mod.__file__ != str(path):
+        modspec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(modspec)
+        sys.modules[name] = mod
+        modspec.loader.exec_module(mod)
+    return mod
 
 
 def model(name: str, hf: dict) -> Model:
-    """A :class:`Model` from the keys of a published ``config.json``."""
-    mt = hf["model_type"]
-    if mt not in _ACTS:
-        raise ValueError(f"model_type {mt!r} has no reference here")
-    act = hf.get("hidden_act", hf.get("hidden_activation"))
-    if act != _ACTS[mt]:
-        raise ValueError(f"{name}: hidden_act {act!r}, expected {_ACTS[mt]!r}")
-    if hf.get("rope_scaling") or hf.get("attention_bias"):
-        raise ValueError(f"{name}: rope scaling and attention biases have no reference here")
-    d, h = hf["hidden_size"], hf["num_attention_heads"]
-    return Model(
-        name=name,
-        kind=mt,
-        n_layers=hf["num_hidden_layers"],
-        d_model=d,
-        n_heads=h,
-        n_kv_heads=hf.get("num_key_value_heads", h),
-        head_dim=hf.get("head_dim") or d // h,
-        d_ff=hf["intermediate_size"],
-        vocab_size=hf["vocab_size"],
-        rope_theta=float(hf.get("rope_theta", 10000.0)),
-        norm_eps=float(hf["rms_norm_eps"]),
-        tie_embeddings=bool(hf.get("tie_word_embeddings", mt == "gemma")),
-        dtype=hf["torch_dtype"],
-        sliding_window=hf.get("sliding_window"),
-    )
+    """A :class:`Model` from the keys of a published ``config.json``,
+    parsed and checked by the family its ``model_type`` names."""
+    return family(hf["model_type"]).model(name, hf)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -21,30 +21,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench import spec
 from chipbench.spec import Model
 
 
 def leaves(m: Model):
-    """``(path, shape)`` of every weight of ``m``; stacked leaves carry the
-    layer count first."""
-    d, hd, nq, nkv = m.d_model, m.head_dim, m.n_heads, m.n_kv_heads
-    L, f, V = m.n_layers, m.d_ff, m.vocab_size
-    out = [(("embed", "tokens"), (V, d)), (("final_norm",), (d,))]
-    if not m.tie_embeddings:
-        out.append((("head",), (d, V)))
-    block = [
-        (("ln1",), (d,)),
-        (("attn", "wq"), (d, nq * hd)),
-        (("attn", "wk"), (d, nkv * hd)),
-        (("attn", "wv"), (d, nkv * hd)),
-        (("attn", "wo"), (nq * hd, d)),
-        (("ln2",), (d,)),
-        (("mlp", "wi"), (d, f)),
-        (("mlp", "wg"), (d, f)),
-        (("mlp", "wo"), (f, d)),
-    ]
-    out += [(("periods", "0") + p, (L,) + s) for p, s in block]
-    return out
+    """``(path, shape)`` of every weight of ``m``, from its family; stacked
+    leaves carry the layer count first."""
+    return spec.family(m.kind).leaves(m)
 
 
 def _is_norm(name: str) -> bool:

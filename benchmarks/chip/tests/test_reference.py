@@ -9,13 +9,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import harness, reference, spec, weights
+from chipbench import reference, spec, weights
+from chipbench.families import gemma, phi3
 
-PHI3 = spec.Model(name="phi3-toy", kind="phi3", n_layers=2, d_model=64, n_heads=4,
+PHI3 = phi3.Model(name="phi3-toy", kind="phi3", n_layers=2, d_model=64, n_heads=4,
                   n_kv_heads=4, head_dim=16, d_ff=128, vocab_size=300,
                   rope_theta=1e4, norm_eps=1e-5, tie_embeddings=False,
                   dtype="bfloat16", sliding_window=2047)
-GEMMA = spec.Model(name="gemma-toy", kind="gemma", n_layers=1, d_model=32, n_heads=2,
+GEMMA = gemma.Model(name="gemma-toy", kind="gemma", n_layers=1, d_model=32, n_heads=2,
                    n_kv_heads=1, head_dim=16, d_ff=128, vocab_size=256,
                    rope_theta=1e4, norm_eps=1e-6, tie_embeddings=True, dtype="float32")
 
@@ -25,8 +26,8 @@ def test_recipe_reproduces_the_served_weights(m):
     from repro.models import transformer as T
 
     seed = 2**31 + 77
-    served = jax.jit(T.init_params, static_argnums=0)(harness.program_model(m),
-                                                      jax.random.key(seed))
+    cfg = spec.family(m.kind).program_model(m)
+    served = jax.jit(T.init_params, static_argnums=0)(cfg, jax.random.key(seed))
     flat = {"/".join(str(k.key if hasattr(k, "key") else k.idx) for k in path): leaf
             for path, leaf in jax.tree_util.tree_leaves_with_path(served)}
     mine = weights.make(m, seed)
@@ -41,7 +42,7 @@ def test_reference_matches_the_program_in_float32(m):
     from repro.models import transformer as T
 
     m32 = dataclasses.replace(m, dtype="float32")
-    cfg = harness.program_model(m32)
+    cfg = spec.family(m32.kind).program_model(m32)
     w = weights.make(m32, 5)
     params = jax.jit(T.init_params, static_argnums=0)(cfg, jax.random.key(5))
     tokens = np.random.default_rng(0).integers(0, m.vocab_size, (2, 24)).astype(np.int32)
